@@ -33,13 +33,15 @@ import argparse
 import sys
 import time
 
-import jax
+from benchmarks.common import Csv, write_bench_json
 
-# init the backend before repro.launch.dryrun pins XLA_FLAGS (the 512
-# virtual dry-run devices are for the real lowering runs, not this smoke)
-jax.devices()
-
-from benchmarks.common import Csv, write_bench_json  # noqa: E402
+from repro.configs import get_arch, list_archs
+from repro.core.decomposer import COMPUTE_DTYPE_BYTES, ep_alltoall_bytes
+from repro.core.e2e import layer_calls, pp_bubble
+from repro.core.hardware import get_hw
+from repro.dist.pipeline import bubble_fraction, schedule_ticks, simulate_schedule
+from repro.launch.dryrun import count_ep_alltoall_bytes
+from repro.predict import CommCall, SweepPredictor, get_predictor
 
 #: the artifact's schema (tests/test_bench_schemas.py gates compare.py
 #: keys against this)
@@ -51,13 +53,6 @@ BENCH_KEYS = (
     "bubble_zb_h1", "zb_ratio", "max_zb_ratio_target",
     "overlap_trace_calls", "overlap_total_ratio", "overlap_bounded",
 )
-from repro.configs import get_arch, list_archs  # noqa: E402
-from repro.core.decomposer import COMPUTE_DTYPE_BYTES, ep_alltoall_bytes  # noqa: E402
-from repro.core.e2e import layer_calls, pp_bubble  # noqa: E402
-from repro.core.hardware import get_hw  # noqa: E402
-from repro.dist.pipeline import bubble_fraction, schedule_ticks, simulate_schedule  # noqa: E402
-from repro.launch.dryrun import count_ep_alltoall_bytes  # noqa: E402
-from repro.predict import CommCall, SweepPredictor, get_predictor  # noqa: E402
 
 #: 1F1B bubble must be at most this fraction of GPipe's at the gate point
 MAX_BUBBLE_RATIO = 0.65

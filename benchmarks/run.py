@@ -36,6 +36,9 @@ def main() -> int:
     ap.add_argument("--json", help="write a machine-readable run summary here")
     args = ap.parse_args()
     from benchmarks.common import Csv
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     known = {tag for tag, _ in MODULES}
     selected = known

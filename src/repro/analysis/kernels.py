@@ -7,7 +7,8 @@ static helpers that mirror the ``pallas_call`` BlockSpecs exactly (pinned
 by direct unit tests); this module derives each registry arch's default
 kernel workloads, evaluates the helpers, and reports:
 
-* SP201 — the double-buffered working set exceeds a device's VMEM;
+* SP201 — the double-buffered working set exceeds the scoped VMEM the
+  compiler grants the kernel (:func:`vmem_budget`);
 * SP202 — a block choice the kernel would reject with an assert
   (non-divisible tiling after the ``min(block, dim)`` clamp);
 * SP203 — a degenerate grid (zero/negative dimension: nothing launches);
@@ -22,6 +23,7 @@ from repro.analysis.diagnostics import Diagnostic
 from repro.configs.base import ArchConfig
 from repro.core.decomposer import COMPUTE_DTYPE_BYTES, moe_dispatch_geometry
 from repro.core.hardware import REGISTRY, TPUSpec
+from repro.kernels import VMEM_LIMIT_BYTES
 from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.fused_moe import ops as moe_ops
 from repro.kernels.rmsnorm import ops as rmsnorm_ops
@@ -38,6 +40,14 @@ KERNEL_HELPERS = {
     "rmsnorm": (rmsnorm_ops.grid_shape, rmsnorm_ops.vmem_footprint),
     "silu_mul": (silu_mul_ops.grid_shape, silu_mul_ops.vmem_footprint),
 }
+
+
+def vmem_budget(hw: TPUSpec) -> int:
+    """Bytes of VMEM one kernel may hold on ``hw``: the scoped limit every
+    kernel requests (``kernels.VMEM_LIMIT_BYTES``), capped by the device's
+    VMEM. The Mosaic compiler refuses a kernel whose double-buffered blocks
+    plus scratch exceed it (``RESOURCE_EXHAUSTED ... scoped vmem limit``)."""
+    return min(int(hw.vmem_mb * 2**20), VMEM_LIMIT_BYTES)
 
 
 def kernel_workloads(
@@ -182,7 +192,7 @@ def check_blocks(
         vm_kw["dtype_bytes"] = dtype_bytes
     footprint = vmem_fn(**kwargs, **vm_kw)
     for hw in hws:
-        budget = hw.vmem_mb * 2**20
+        budget = vmem_budget(hw)
         if footprint > budget:
             diags.append(
                 Diagnostic(
@@ -190,9 +200,9 @@ def check_blocks(
                     severity="error",
                     check="kernel-resource",
                     message=(
-                        f"{name} working set {footprint / 2**20:.1f} MiB overflows "
-                        f"{hw.name} VMEM ({hw.vmem_mb:g} MiB) with blocks "
-                        f"{blocks or 'default'} — the compile would spill or abort"
+                        f"{name} working set {footprint / 2**20:.2f} MiB overflows "
+                        f"the {budget / 2**20:g} MiB of scoped VMEM on {hw.name} "
+                        f"with blocks {blocks or 'default'} — the compiler refuses it"
                     ),
                     arch=arch,
                     where=f"kernels/{name}:vmem_footprint {kwargs} on {hw.name}",
@@ -200,7 +210,7 @@ def check_blocks(
                         "kernel": name,
                         "hw": hw.name,
                         "footprint_bytes": footprint,
-                        "vmem_bytes": int(budget),
+                        "vmem_bytes": budget,
                         "blocks": blocks,
                     },
                 )

@@ -73,7 +73,6 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = [
@@ -307,12 +306,12 @@ def _forward_gpipe(layer_fn, params, x, mesh, axis, ticks=None):
         return lax.psum(jnp.where(stage == n_stages - 1, outputs, 0.0), axis)
 
     pspecs = jax.tree.map(lambda _: P(axis), params)
-    return shard_map(
+    return jax.shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=(pspecs, P()),
         out_specs=P(),
-        check_rep=False,  # ppermute-carried state is intentionally unreplicated
+        check_vma=False,  # ppermute-carried state is intentionally unreplicated
     )(params, x)
 
 
@@ -412,10 +411,10 @@ def _forward_ring(layer_fn, params, x, mesh, axis, interleave, ticks=None,
         return lax.psum(jnp.where(stage == n_stages - 1, outputs, 0.0), axis)
 
     pspecs = jax.tree.map(lambda _: P(None, axis), chunked)
-    return shard_map(
+    return jax.shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=(pspecs, P()),
         out_specs=P(),
-        check_rep=False,  # ppermute-carried state is intentionally unreplicated
+        check_vma=False,  # ppermute-carried state is intentionally unreplicated
     )(chunked, x)

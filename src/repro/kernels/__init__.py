@@ -1,16 +1,27 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels: one package per kernel family, each with the
+``pallas_call`` (``kernel.py``), its jit'd entry point with static
+grid/VMEM helpers (``ops.py``) and a pure-jnp oracle (``ref.py``)."""
+from __future__ import annotations
+
+from typing import Optional
+
+#: Scoped-VMEM limit every kernel requests from the Mosaic compiler
+#: (``vmem_limit_bytes``). Without it the compiler enforces its own default
+#: (16 MiB on v5e), which refuses dbrx-width ``fused_moe`` and
+#: d_ff=22016 ``silu_mul``. 64 MiB is the smallest VMEM in the hardware
+#: registry and half of a v5e's 128 MiB. The static SP201 lint
+#: (``repro.analysis.kernels``) budgets exactly this limit.
+VMEM_LIMIT_BYTES = 64 * 2**20
 
 
-def tpu_compiler_params(**kwargs):
-    """Pallas TPU compiler params across jax versions: the class is
-    ``pltpu.CompilerParams`` on jax >= 0.5 and ``pltpu.TPUCompilerParams``
-    on jax 0.4.x (same keyword surface for what we use)."""
-    from jax.experimental.pallas import tpu as pltpu
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """The ``interpret`` mode a kernel runs in: compiled on a TPU backend,
+    interpreted everywhere else; an explicit bool always wins."""
+    if interpret is not None:
+        return interpret
+    import jax
 
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return jax.default_backend() != "tpu"
 
 
 def largest_divisor_block(total: int, block: int) -> int:

@@ -60,7 +60,7 @@ def attention(
     softcap: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
     use_pallas: bool = True,
 ):
     B, S, Hq, D = q.shape

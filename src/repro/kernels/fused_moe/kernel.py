@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
+from repro.kernels import VMEM_LIMIT_BYTES, resolve_interpret
 
 
 def _moe_kernel(
@@ -68,7 +68,7 @@ def fused_moe_pallas(
     *,
     block_m: int = 128,
     block_f: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     E, C, D = x.shape
     F = w_gate.shape[2]
@@ -90,8 +90,9 @@ def fused_moe_pallas(
         out_specs=pl.BlockSpec((1, block_m, D), lambda e, im, jf: (e, im, 0)),
         out_shape=jax.ShapeDtypeStruct((E, C, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, D), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w_gate, w_up, w_down)

@@ -39,7 +39,7 @@ def vmem_footprint(
 
 @partial(jax.jit, static_argnames=("block_m", "block_f", "interpret", "use_pallas"))
 def fused_moe(
-    x, w_gate, w_up, w_down, *, block_m=128, block_f=256, interpret=True, use_pallas=True
+    x, w_gate, w_up, w_down, *, block_m=128, block_f=256, interpret=None, use_pallas=True
 ):
     if not use_pallas:
         return fused_moe_ref(x, w_gate, w_up, w_down)

@@ -6,8 +6,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import largest_divisor_block
+from repro.kernels import VMEM_LIMIT_BYTES, largest_divisor_block, resolve_interpret
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -17,7 +18,9 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
     o_ref[...] = o.astype(o_ref.dtype)
 
 
-def rmsnorm_pallas(x, w, *, eps: float = 1e-6, block_rows: int = 256, interpret: bool = True):
+def rmsnorm_pallas(
+    x, w, *, eps: float = 1e-6, block_rows: int = 256, interpret: bool | None = None
+):
     orig_shape = x.shape
     d = x.shape[-1]
     xf = x.reshape(-1, d)
@@ -32,6 +35,9 @@ def rmsnorm_pallas(x, w, *, eps: float = 1e-6, block_rows: int = 256, interpret:
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, d), x.dtype),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT_BYTES
+        ),
+        interpret=resolve_interpret(interpret),
     )(xf, w)
     return out.reshape(orig_shape)

@@ -22,7 +22,7 @@ def vmem_footprint(R: int, d: int, *, block_rows: int = 256, dtype_bytes: int = 
 
 
 @partial(jax.jit, static_argnames=("eps", "block_rows", "interpret", "use_pallas"))
-def rmsnorm(x, w, *, eps=1e-6, block_rows=256, interpret=True, use_pallas=True):
+def rmsnorm(x, w, *, eps=1e-6, block_rows=256, interpret=None, use_pallas=True):
     if not use_pallas:
         return rmsnorm_ref(x, w, eps)
     return rmsnorm_pallas(x, w, eps=eps, block_rows=block_rows, interpret=interpret)

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import largest_divisor_block, tpu_compiler_params
+from repro.kernels import VMEM_LIMIT_BYTES, largest_divisor_block, resolve_interpret
 
 
 def _scaled_mm_kernel(
@@ -33,10 +33,11 @@ def _scaled_mm_kernel(
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    x = x_ref[...].astype(jnp.int32)
-    w = w_ref[...].astype(jnp.int32)
+    # int8 operands straight into the MXU with int32 accumulation (Mosaic
+    # has no int32 x int32 matmul); the products are exact either way
     acc_scr[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
+        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32,
     )
 
     @pl.when(ik == n_k - 1)
@@ -55,7 +56,7 @@ def scaled_mm_pallas(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     M, K = x.shape
     N = w.shape[1]
@@ -75,8 +76,9 @@ def scaled_mm_pallas(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w, sx[:, None].astype(jnp.float32), sw[None, :].astype(jnp.float32))

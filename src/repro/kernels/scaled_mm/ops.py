@@ -40,7 +40,7 @@ def vmem_footprint(
 @partial(jax.jit, static_argnames=("out_dtype", "block_m", "block_n", "block_k",
                                    "interpret", "use_pallas"))
 def scaled_mm(x, w, sx, sw, *, out_dtype=jnp.bfloat16, block_m=128, block_n=128,
-              block_k=256, interpret=True, use_pallas=True):
+              block_k=256, interpret=None, use_pallas=True):
     if not use_pallas:
         return scaled_mm_ref(x, w, sx, sw, out_dtype)
     return scaled_mm_pallas(

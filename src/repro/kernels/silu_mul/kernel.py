@@ -6,8 +6,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import largest_divisor_block
+from repro.kernels import VMEM_LIMIT_BYTES, largest_divisor_block, resolve_interpret
 
 
 def _silu_mul_kernel(g_ref, u_ref, o_ref, *, act: str):
@@ -20,7 +21,9 @@ def _silu_mul_kernel(g_ref, u_ref, o_ref, *, act: str):
     o_ref[...] = (h * u).astype(o_ref.dtype)
 
 
-def silu_mul_pallas(g, u, *, act: str = "silu", block_rows: int = 128, interpret: bool = True):
+def silu_mul_pallas(
+    g, u, *, act: str = "silu", block_rows: int = 128, interpret: bool | None = None
+):
     orig_shape = g.shape
     d = g.shape[-1]
     gf, uf = g.reshape(-1, d), u.reshape(-1, d)
@@ -35,6 +38,9 @@ def silu_mul_pallas(g, u, *, act: str = "silu", block_rows: int = 128, interpret
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, d), g.dtype),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT_BYTES
+        ),
+        interpret=resolve_interpret(interpret),
     )(gf, uf)
     return out.reshape(orig_shape)

@@ -22,7 +22,7 @@ def vmem_footprint(R: int, d: int, *, block_rows: int = 128, dtype_bytes: int = 
 
 
 @partial(jax.jit, static_argnames=("act", "block_rows", "interpret", "use_pallas"))
-def act_mul(g, u, *, act="silu", block_rows=128, interpret=True, use_pallas=True):
+def act_mul(g, u, *, act="silu", block_rows=128, interpret=None, use_pallas=True):
     if not use_pallas:
         return silu_mul_ref(g, u, act=act)
     return silu_mul_pallas(g, u, act=act, block_rows=block_rows, interpret=interpret)

@@ -1,9 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 " + os.environ.get("XLA_FLAGS", "")
-)
-
 """Multi-pod dry-run: prove the distribution config is coherent without
 hardware.
 
@@ -20,6 +14,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -248,8 +243,6 @@ def analyze(lowered, compiled, meta) -> dict:
     from repro.roofline.hlo_cost import analyze_hlo
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):  # jax 0.4.x returns a one-element list
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_d = {
@@ -320,6 +313,11 @@ def main():
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--out", default="results/dryrun")
     args = ap.parse_args()
+    # the production meshes need 512 virtual host devices; the flag must be
+    # in the environment before the backend initializes
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 " + os.environ.get("XLA_FLAGS", "")
+    )
 
     os.makedirs(args.out, exist_ok=True)
     cells = all_cells() if args.all else [(args.arch, args.shape)]

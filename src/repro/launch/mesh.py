@@ -1,10 +1,24 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 Defined as functions (not module-level constants) so importing this module
-never touches jax device state."""
+never touches jax device state. :func:`make_mesh` is the one constructor:
+every mesh in the repo is built through it."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """A mesh of ``shape`` over ``axes`` with every axis ``AxisType.Auto``,
+    so shardings propagate through ``jnp`` ops as the models' ``constrain``
+    hints expect (``jax.make_mesh`` alone defaults to Explicit axes, under
+    which a sharded gather must name its output sharding). ``devices``
+    defaults to ``jax.devices()``."""
+    axes = tuple(axes)
+    return jax.make_mesh(
+        tuple(shape), axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False, pipeline: bool = False):
@@ -22,7 +36,7 @@ def make_production_mesh(*, multi_pod: bool = False, pipeline: bool = False):
     else:
         shape = (2, 16, 16) if multi_pod else (16, 16)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def mesh_tag(*, multi_pod: bool = False, pipeline: bool = False) -> str:
@@ -30,8 +44,3 @@ def mesh_tag(*, multi_pod: bool = False, pipeline: bool = False) -> str:
     if pipeline:
         return "2x4x8x8pp" if multi_pod else "4x8x8pp"
     return "2x16x16" if multi_pod else "16x16"
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh for tests / small-scale runs."""
-    return jax.make_mesh(tuple(shape), tuple(axes))

@@ -35,20 +35,15 @@ def main():
             + os.environ.get("XLA_FLAGS", "")
         )
 
-    import jax
-
     from repro.configs import get_arch
     from repro.data.pipeline import DataConfig
-    from repro.dist.sharding import batch_pspecs, to_named, use_mesh
-    from repro.train.step import (
-        TrainConfig,
-        init_train_state,
-        make_optimizer,
-        train_state_pspecs,
-    )
-    from repro.train.trainer import Trainer, TrainerConfig
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import make_mesh
+    from repro.train.step import TrainConfig
+    from repro.train.trainer import TrainerConfig
 
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(name)s %(message)s")
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
@@ -66,39 +61,46 @@ def main():
         ckpt_dir=args.ckpt_dir,
         async_save=args.async_save,
     )
-
     mesh = None
-    state_sh = batch_sh = None
     if args.mesh:
         r, c = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((r, c), ("data", "model"))
-
-    if mesh is not None:
-        with use_mesh(mesh):
-            from repro.models.registry import build_model
-
-            api = build_model(cfg)
-            optimizer = make_optimizer(tc)
-            state_shapes = jax.eval_shape(
-                lambda: init_train_state(
-                    api, optimizer, jax.random.PRNGKey(0),
-                    compress_grads=tc.compress_grads,
-                )
-            )
-            state_sh = to_named(train_state_pspecs(state_shapes, mesh), mesh)
-            from repro.models.registry import batch_specs
-
-            batch_sh = to_named(
-                batch_pspecs(batch_specs(cfg, args.batch, args.seq), mesh), mesh
-            )
-            trainer = Trainer(cfg, data, tc, tcfg, mesh=mesh,
-                              state_shardings=state_sh, batch_shardings=batch_sh)
-            step, _, losses = trainer.run()
-    else:
-        trainer = Trainer(cfg, data, tc, tcfg)
-        step, _, losses = trainer.run()
+        mesh = make_mesh((r, c), ("data", "model"))
+    step, _, losses = train(cfg, data, tc, tcfg, mesh=mesh)
     print(f"finished at step {step}; loss {losses[0]:.4f} -> {losses[-1]:.4f}")
     return 0
+
+
+def train(cfg, data, tc, tcfg, *, mesh=None, seed: int = 0):
+    """Build the :class:`~repro.train.trainer.Trainer` and run it; returns
+    ``(final_step, state, losses)``. With ``mesh`` the train state and the
+    batches are placed by the ``dist.sharding`` rules and every step runs
+    under ``use_mesh(mesh)``."""
+    from repro.train.trainer import Trainer
+
+    if mesh is None:
+        return Trainer(cfg, data, tc, tcfg).run(seed=seed)
+
+    import jax
+
+    from repro.dist.sharding import batch_pspecs, to_named, use_mesh
+    from repro.models.registry import batch_specs, build_model
+    from repro.train.step import init_train_state, make_optimizer, train_state_pspecs
+
+    with use_mesh(mesh):
+        api = build_model(cfg)
+        state_shapes = jax.eval_shape(
+            lambda: init_train_state(
+                api, make_optimizer(tc), jax.random.PRNGKey(seed),
+                compress_grads=tc.compress_grads,
+            )
+        )
+        state_sh = to_named(train_state_pspecs(state_shapes, mesh), mesh)
+        batch_sh = to_named(
+            batch_pspecs(batch_specs(cfg, data.batch, data.seq_len), mesh), mesh
+        )
+        trainer = Trainer(cfg, data, tc, tcfg, mesh=mesh,
+                          state_shardings=state_sh, batch_shardings=batch_sh)
+        return trainer.run(seed=seed)
 
 
 if __name__ == "__main__":

@@ -62,9 +62,9 @@ class Result:
     #: plus every decode tick it took a token in) — comparable across the
     #: batch and continuous engines, and to fleet-simulator service ticks
     ticks: int = 0
-    #: admission-to-retire wall-clock of this process. For the reference
-    #: CPU engines this is a functional metric only; the fleet simulator's
-    #: queueing latency is the *predicted* analogue on target hardware.
+    #: admission-to-retire wall-clock of this process, on whichever backend
+    #: the engine runs (CPU or TPU); the fleet simulator's queueing latency
+    #: is the *predicted* analogue on the predictor's hardware.
     latency_s: float = 0.0
 
 
@@ -314,12 +314,12 @@ class ContinuousBatchingEngine(_EngineBase):
         a tick generates one token per *active* slot;
       * the *attended* KV span of a tick is ``max(active positions) + 1``
         (the logical work the decomposer and the hwsim oracle price); the
-        reference masked decode kernel physically sweeps the padded cache,
-        so wall-clock of this CPU process is not the modeled latency;
+        masked decode step physically sweeps the padded cache, so the
+        measured tick time on CPU or TPU is not the modeled latency;
       * all latencies in the admission machinery are **seconds predicted
-        on the admission predictor's hardware**, not host wall-clock —
-        this engine is a functional reference, the predictor is the model
-        of the serving fleet.
+        on the admission predictor's hardware**, not measured wall-clock —
+        the predictor is the model of the serving fleet, whatever backend
+        this engine runs on.
 
     Admission policy (``admission=``):
 

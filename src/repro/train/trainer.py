@@ -88,6 +88,10 @@ class Trainer:
             step, state, extra = restored
             log.info("resumed from checkpoint step %d", step)
             return int(step), state
+        if self.state_shardings is not None:
+            # place the fresh state as the step returns it: an unplaced
+            # first input would make the second step compile again
+            state = jax.device_put(state, self.state_shardings)
         return 0, state
 
     def request_preemption(self, *_args):

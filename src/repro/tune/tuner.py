@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core import hwsim
 from repro.core.hardware import REGISTRY, TPUSpec
+from repro.kernels import resolve_interpret
 from repro.predict.api import KernelCall, Predictor
 from repro.tune.space import (
     DEFAULT_WORKLOADS,
@@ -223,12 +224,11 @@ def measure(
     interpret: Optional[bool] = None,
 ) -> float:
     """Wall-clock seconds of one timed ``pallas_call`` execution: one
-    warmup (compile) run, then min over ``repeats``. ``interpret`` defaults
-    to True off-accelerator (CPU CI) and False when a real backend is up."""
+    warmup (compile) run, then min over ``repeats``. ``interpret`` resolves
+    through :func:`repro.kernels.resolve_interpret` (compiled on a TPU)."""
     import jax
 
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = resolve_interpret(interpret)
     if args is None:
         args = make_inputs(kernel, kw)
     call = functools.partial(kernel_entry(kernel), *args, interpret=interpret, **blocks)
@@ -348,8 +348,6 @@ def tune(
         measured.append(c)
     best = min(measured, key=lambda c: c.measured_s or float("inf"))
 
-    import jax
-
     return TuneReport(
         kernel=kernel,
         hw=hw.name,
@@ -361,7 +359,7 @@ def tune(
         measured=measured,
         best=best,
         t_default=t_default,
-        interpret=(jax.default_backend() == "cpu") if interpret is None else interpret,
+        interpret=resolve_interpret(interpret),
         predictor=predictor_name or (type(predictor).__name__ if predictor else "oracle"),
     )
 

@@ -11,6 +11,8 @@ import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
+
 
 def _run_sub(script: str, devices: int = 8, timeout: int = 480):
     env = dict(os.environ)
@@ -77,7 +79,7 @@ def test_param_rules_cover_all_archs():
         jax.tree_util.tree_map_with_path(one, shapes)
         return names
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for name in list_archs():
         cfg = get_arch(name).smoke()
         api = build_model(cfg)
@@ -103,6 +105,7 @@ def test_sharded_train_step_matches_single_device():
     _run_sub(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_arch
         from repro.models.registry import build_model, materialize_batch
         from repro.dist.sharding import param_pspecs, batch_pspecs, to_named, use_mesh
@@ -112,7 +115,7 @@ def test_sharded_train_step_matches_single_device():
         batch = materialize_batch(cfg, 4, 32)
         loss_single, _ = jax.jit(api.loss)(params, batch)
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         with use_mesh(mesh):
             p_sh = to_named(param_pspecs(params, mesh), mesh)
             b_sh = to_named(batch_pspecs(batch, mesh), mesh)
@@ -130,6 +133,7 @@ def test_moe_expert_parallel_matches_single_device():
     _run_sub(
         """
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_arch
         from repro.models.registry import build_model, materialize_batch
         from repro.dist.sharding import param_pspecs, batch_pspecs, to_named, use_mesh
@@ -139,7 +143,7 @@ def test_moe_expert_parallel_matches_single_device():
         params = api.init(jax.random.PRNGKey(0))
         batch = materialize_batch(cfg, 4, 32)
         loss_single, _ = jax.jit(api.loss)(params, batch)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         with use_mesh(mesh):
             p_sh = to_named(param_pspecs(params, mesh), mesh)
             b_sh = to_named(batch_pspecs(batch, mesh), mesh)
@@ -157,9 +161,10 @@ def test_pipeline_parallel_matches_sequential():
     _run_sub(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax import lax
         from repro.dist.pipeline import pipeline_forward
-        mesh = jax.make_mesh((4,), ("pipe",))
+        mesh = make_mesh((4,), ("pipe",))
         n_layers, micro, mb, d = 8, 4, 2, 16
         ks = jax.random.split(jax.random.PRNGKey(0), n_layers)
         params = {"w": jax.vmap(lambda k: 0.3*jax.random.normal(k, (d, d)))(ks)}
@@ -187,9 +192,10 @@ def test_pipeline_1f1b_matches_sequential_at_exact_tick_count():
     _run_sub(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax import lax
         from repro.dist.pipeline import pipeline_forward, schedule_ticks
-        mesh = jax.make_mesh((4,), ("pipe",))
+        mesh = make_mesh((4,), ("pipe",))
         layer_fn = lambda lp, h: jnp.tanh(h @ lp["w"])
         def seq(params, x):
             def body(c, lp):
@@ -234,9 +240,10 @@ def test_pipeline_zb_h1_matches_sequential_at_exact_tick_count():
     _run_sub(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax import lax
         from repro.dist.pipeline import pipeline_forward, schedule_ticks
-        mesh = jax.make_mesh((4,), ("pipe",))
+        mesh = make_mesh((4,), ("pipe",))
         layer_fn = lambda lp, h: jnp.tanh(h @ lp["w"])
         def seq(params, x):
             def body(c, lp):
@@ -271,10 +278,11 @@ def test_bucketed_ef_allreduce_transport_matches_sync():
         """
         import functools
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.dist.collectives import ef_compress_grads, ef_compress_grads_bucketed
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         grads = {
             "w1": jnp.asarray(rng.standard_normal((8, 64, 16)), jnp.float32),
@@ -319,6 +327,7 @@ def test_elastic_restart_across_device_counts():
     _run_sub(
         """
         import jax, numpy as np, tempfile, os
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_arch
         from repro.data.pipeline import DataConfig
         from repro.train.step import TrainConfig
@@ -332,7 +341,7 @@ def test_elastic_restart_across_device_counts():
         t = mk(2); t.run(seed=0)
         # "restart" with a different sharded mesh
         from repro.dist.sharding import param_pspecs, to_named, use_mesh
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         from repro.train.step import init_train_state, make_optimizer
         from repro.optim.adamw import AdamWState
         from jax.sharding import PartitionSpec as P

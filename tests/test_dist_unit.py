@@ -26,6 +26,7 @@ from repro.dist.sharding import (
     to_named,
     use_mesh,
 )
+from repro.launch.mesh import make_mesh
 
 
 # ----------------------------------------------------------------------
@@ -34,7 +35,6 @@ from repro.dist.sharding import (
 
 
 def _mesh(sizes, names):
-    # two-arg AbstractMesh; conftest normalizes the signature on jax 0.4.x
     from jax.sharding import AbstractMesh
 
     return AbstractMesh(sizes, names)
@@ -124,7 +124,7 @@ def test_use_mesh_nesting_and_constrain_noop():
     assert active_mesh() is None
     x = jnp.ones((4, 8))
     assert constrain(x, ("batch", None)) is x  # no mesh -> identity
-    m1 = jax.make_mesh((1,), ("data",))
+    m1 = make_mesh((1,), ("data",))
     with use_mesh(m1) as m:
         assert active_mesh() is m1 and m is m1
         with use_mesh(m1):
@@ -134,7 +134,7 @@ def test_use_mesh_nesting_and_constrain_noop():
 
 
 def test_to_named_wraps_specs_and_passes_none_through():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     tree = {"a": P("data", None), "b": None, "c": {"d": P()}}
     out = to_named(tree, mesh)
     assert isinstance(out["a"], NamedSharding) and out["a"].spec == P("data", None)

@@ -1,5 +1,4 @@
-"""Property suite for the drift control loop (ISSUE 9, hypothesis; falls
-back to tests/_hypothesis_stub.py when the real package is absent):
+"""Property suite for the drift control loop (hypothesis):
 
   * an undrifted monitored replay is bit-identical to the frozen
     vectorized path and trips zero re-routes (false-positive bound);

@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.launch.mesh import make_mesh
 from repro.roofline.hlo_cost import analyze_hlo
 
 
@@ -75,7 +76,7 @@ def test_hbm_bytes_reasonable():
 
 
 def test_collectives_counted_under_sharding():
-    mesh = jax.make_mesh((1,), ("d",))
+    mesh = make_mesh((1,), ("d",))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def f(x):

@@ -100,11 +100,14 @@ def test_silu_mul_static_helpers():
 
 def test_silu_mul_default_fits_smallest_vmem_for_largest_dff():
     """The auditor-motivated default: deepseek's d_ff=22016 must fit the
-    64 MiB registry devices (the original 256-row default was 64.5 MiB)."""
+    smallest scoped-VMEM budget in the registry (the original 256-row
+    default was 64.5 MiB)."""
+    from repro.analysis.kernels import vmem_budget
     from repro.core.hardware import REGISTRY
 
-    min_vmem = min(hw.vmem_mb for hw in REGISTRY.values()) * 2**20
+    min_vmem = min(vmem_budget(hw) for hw in REGISTRY.values())
     assert silu_ops.vmem_footprint(1024, 22016, dtype_bytes=2) <= min_vmem
+    assert silu_ops.vmem_footprint(1024, 22016, block_rows=256, dtype_bytes=2) > min_vmem
 
 
 # ---------------------------------------------------------------------------

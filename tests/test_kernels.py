@@ -1,17 +1,42 @@
 """Per-kernel validation: Pallas (interpret=True, the CPU-executable path of
 the TPU kernels) vs pure-jnp oracles, swept over shapes/dtypes/block sizes."""
+import inspect
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import resolve_interpret
+from repro.kernels.flash_attention import kernel as fa_kernel
 from repro.kernels.flash_attention import ops as fa_ops
+from repro.kernels.fused_moe import kernel as moe_kernel
 from repro.kernels.fused_moe import ops as moe_ops
 from repro.kernels.fused_moe.ref import fused_moe_ref
+from repro.kernels.rmsnorm import kernel as rms_kernel
 from repro.kernels.rmsnorm import ops as rms_ops
 from repro.kernels.rmsnorm.ref import rmsnorm_ref
+from repro.kernels.scaled_mm import kernel as mm_kernel
+from repro.kernels.scaled_mm import ops as mm_ops
+from repro.kernels.silu_mul import kernel as silu_kernel
 from repro.kernels.silu_mul import ops as silu_ops
 from repro.kernels.silu_mul.ref import silu_mul_ref
+
+
+def test_resolve_interpret_follows_the_backend():
+    assert resolve_interpret(None) is (jax.default_backend() != "tpu")
+    assert resolve_interpret(True) is True and resolve_interpret(False) is False
+
+
+@pytest.mark.parametrize("fn", [
+    fa_ops.attention, moe_ops.fused_moe, rms_ops.rmsnorm, mm_ops.scaled_mm, silu_ops.act_mul,
+    fa_kernel.flash_attention_pallas, moe_kernel.fused_moe_pallas, rms_kernel.rmsnorm_pallas,
+    mm_kernel.scaled_mm_pallas, silu_kernel.silu_mul_pallas,
+], ids=lambda f: f.__name__)
+def test_entry_points_default_to_the_backend_mode(fn):
+    """No kernel entry point pins interpret mode: ``None`` resolves to
+    compiled on a TPU and interpreted elsewhere."""
+    assert inspect.signature(fn).parameters["interpret"].default is None
 
 
 def _tol(dtype):

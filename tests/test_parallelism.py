@@ -11,31 +11,23 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-import jax
-
-# initialize the backend before importing repro.launch.dryrun: that module
-# pins XLA_FLAGS to 512 virtual devices at import time for the real
-# dry-run; with the backend already up the flag is inert and the byte
-# counters run on the normal single-device test process
-jax.devices()
-
-from repro.configs import get_arch, list_archs  # noqa: E402
-from repro.core.decomposer import (  # noqa: E402
+from repro.configs import get_arch, list_archs
+from repro.core.decomposer import (
     COMPUTE_DTYPE_BYTES,
     ep_alltoall_bytes,
     moe_dispatch_geometry,
 )
-from repro.core.e2e import layer_calls, pp_bubble, request_estimate  # noqa: E402
-from repro.core.hardware import get_hw  # noqa: E402
-from repro.dist.pipeline import (  # noqa: E402
+from repro.core.e2e import layer_calls, pp_bubble, request_estimate
+from repro.core.hardware import get_hw
+from repro.dist.pipeline import (
     bubble_fraction,
     pipeline_bubble_fraction,
     schedule_ticks,
     simulate_schedule,
 )
-from repro.launch.dryrun import count_ep_alltoall_bytes  # noqa: E402
-from repro.predict import CommCall, CommRegressor, SweepPredictor, get_predictor  # noqa: E402
-from repro.serve.trace import TraceRecorder  # noqa: E402
+from repro.launch.dryrun import count_ep_alltoall_bytes
+from repro.predict import CommCall, CommRegressor, SweepPredictor, get_predictor
+from repro.serve.trace import TraceRecorder
 
 HW = get_hw("tpu-v5e")
 

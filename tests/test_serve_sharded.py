@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_arch
+from repro.launch.mesh import make_mesh
 from repro.serve.engine import ContinuousBatchingEngine, Request, ServeEngine
 from repro.serve.trace import TraceRecorder
 
@@ -56,11 +57,12 @@ _SHARDED_SERVE = """
     import numpy as np, jax
     from repro.configs import get_arch
     from repro.serve.engine import ServeEngine, ContinuousBatchingEngine, Request
+    from repro.launch.mesh import make_mesh
     from repro.serve.trace import TraceRecorder
 
     assert jax.device_count() == 8
     cfg = dataclasses.replace(get_arch("qwen3-0.6b").smoke(), compute_dtype="float32")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     prompts = [np.arange(1, 9 + i, dtype=np.int32) for i in range(4)]
 
     eng1 = ServeEngine(cfg, seed=0, max_batch=4)
@@ -111,7 +113,7 @@ def test_sharded_engines_match_single_process_subprocess():
 @pytest.mark.skipif(jax.device_count() < 8, reason="needs 8 devices (CI multi-device leg)")
 def test_sharded_serve_engine_matches_in_process():
     cfg = _f32_smoke()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     prompts = [np.arange(1, 7 + i, dtype=np.int32) for i in range(3)]
     eng1 = ServeEngine(cfg, seed=0, max_batch=4)
     for i, p in enumerate(prompts):

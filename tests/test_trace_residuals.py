@@ -9,6 +9,7 @@ import pytest
 
 from repro.configs import get_arch
 from repro.core.hardware import get_hw
+from repro.launch.mesh import make_mesh
 from repro.predict import get_predictor
 from repro.serve.engine import ContinuousBatchingEngine, Request, ServeEngine
 from repro.serve.monitor import (
@@ -109,7 +110,7 @@ def test_round_trip_at_declared_degrees(predictor):
 def test_continuous_engine_mesh_inherited_degrees(cfg, predictor):
     # a mesh-native engine binds the recorder to its mesh axes; the
     # recorded meta carries those degrees and still round-trips
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rec = TraceRecorder()
     eng = ContinuousBatchingEngine(cfg, slots=2, max_len=48,
                                    recorder=rec, mesh=mesh)
