@@ -63,6 +63,7 @@ def init_norm(key, cfg: ArchConfig, d: int, dtype):
     return {"w": jnp.zeros((d,), dtype)}  # rmsnorm stores (scale - 1)
 
 
+@jax.named_scope("norm")
 def apply_norm(p, x, cfg: ArchConfig):
     if cfg.norm == "layernorm":
         return layernorm(x, p["w"], p["b"])
@@ -339,6 +340,7 @@ def _project_qkv(p, x, cfg: ArchConfig, positions):
     return q, k, v
 
 
+@jax.named_scope("attention")
 def attention_layer(
     p,
     x,
@@ -380,6 +382,7 @@ def attention_layer(
     return out, (k, v)
 
 
+@jax.named_scope("attention")
 def attention_decode(
     p,
     x,  # (B, 1, d)
@@ -393,12 +396,13 @@ def attention_decode(
     """Single-token decode against a KV cache; returns (out, new_k, new_v)."""
     B = x.shape[0]
     q, k, v = _project_qkv(p, x, cfg, positions[:, None])
-    cache_k = jax.vmap(lambda c, u, i: lax.dynamic_update_slice_in_dim(c, u, i, 0))(
-        cache_k, k, positions
-    )
-    cache_v = jax.vmap(lambda c, u, i: lax.dynamic_update_slice_in_dim(c, u, i, 0))(
-        cache_v, v, positions
-    )
+    with jax.named_scope("kv_cache"):
+        cache_k = jax.vmap(lambda c, u, i: lax.dynamic_update_slice_in_dim(c, u, i, 0))(
+            cache_k, k, positions
+        )
+        cache_v = jax.vmap(lambda c, u, i: lax.dynamic_update_slice_in_dim(c, u, i, 0))(
+            cache_v, v, positions
+        )
     Smax = cache_k.shape[1]
     kpos = jnp.broadcast_to(jnp.arange(Smax, dtype=jnp.int32)[None], (B, Smax))
     valid = kpos <= positions[:, None]
@@ -415,6 +419,7 @@ def init_cross_attention(key, cfg: ArchConfig, dtype):
     return init_attention(key, cfg, dtype)
 
 
+@jax.named_scope("attention")
 def cross_attention_layer(p, x, kv_src, cfg: ArchConfig):
     """Cross-attention: queries from x, keys/values from kv_src (no RoPE)."""
     B, S, _ = x.shape
@@ -435,6 +440,7 @@ def cross_attention_layer(p, x, kv_src, cfg: ArchConfig):
     return out.reshape(B, S, -1) @ p["wo"], (k, v)
 
 
+@jax.named_scope("attention")
 def cross_attention_cached(p, x, ck, cv, cfg: ArchConfig):
     """Cross-attention at decode time against precomputed source K/V."""
     B, S, _ = x.shape
@@ -469,6 +475,7 @@ def init_ffn(key, cfg: ArchConfig, dtype, d_ff: Optional[int] = None):
     return {"w_up": dense_init(k1, (d, f), dtype), "w_down": dense_init(k2, (f, d), dtype)}
 
 
+@jax.named_scope("ffn")
 def ffn(p, x, cfg: ArchConfig, use_pallas: bool = False):
     if cfg.act in ("silu", "geglu"):
         g = x @ p["w_gate"]
@@ -499,6 +506,7 @@ def init_embed(key, cfg: ArchConfig, dtype):
     }
 
 
+@jax.named_scope("embed")
 def embed_tokens(p, tokens, cfg: ArchConfig, compute_dtype):
     x = jnp.take(p["tok"], tokens, axis=0).astype(compute_dtype)
     if cfg.embed_scale:
@@ -506,6 +514,7 @@ def embed_tokens(p, tokens, cfg: ArchConfig, compute_dtype):
     return x
 
 
+@jax.named_scope("lm_head")
 def lm_logits(p, x, cfg: ArchConfig):
     logits = (x @ p["head"].astype(x.dtype)).astype(jnp.float32)
     if cfg.final_softcap is not None:

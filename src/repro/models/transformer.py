@@ -322,7 +322,10 @@ class Segment:
     def init(self, key):
         return jax.vmap(self.init_one)(jax.random.split(key, self.n))
 
+    @jax.named_scope("layer_scan")
     def apply(self, params, x, ctx: Ctx, mode: str, cache=None, remat=False):
+        """Scan the stack; its slicing and restacking of weights and
+        caches lands under the ``layer_scan`` scope."""
         fwd = self.fwd
 
         if mode == "train":
@@ -567,10 +570,11 @@ def train_loss(params, cfg: ArchConfig, batch):
     tokens = batch["tokens"]
     labels = tokens[:, 1:]
     valid = jnp.ones_like(labels, jnp.float32)
-    ce = L.chunked_cross_entropy(
-        hidden[:, :-1, :], params["embed"], labels, valid, cfg, block=cfg.q_block
-    )
-    loss = ce + 0.01 * aux
+    with jax.named_scope("loss"):
+        ce = L.chunked_cross_entropy(
+            hidden[:, :-1, :], params["embed"], labels, valid, cfg, block=cfg.q_block
+        )
+        loss = ce + 0.01 * aux
     return loss, {"ce": ce, "aux": aux}
 
 

@@ -31,6 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 import repro.models.transformer as T
 from repro.configs.base import ArchConfig
@@ -348,6 +349,16 @@ class ContinuousBatchingEngine(_EngineBase):
     is (B_slots, max_len, ...); per-slot prefill recomputes the prompt with
     the slot's row batched alone and writes its KV into the slot row
     (dynamic_update_slice), so running requests are never interrupted.
+
+    Profiler spans (``jax.profiler.TraceAnnotation``, no-ops unless a
+    profiler session is running; docs/serving.md shows how to read them):
+    ``engine.step`` around each tick, holding one ``engine.admit`` per
+    admitted request (args ``rid``, ``prompt_len``, ``queue_wait_ms`` since
+    ``submit``, ``queued`` left behind it; children ``engine.prefill``,
+    ``engine.pad_cache``, ``engine.slot_write``, ``engine.first_token``),
+    ``engine.decode`` (``active``, ``slots``, ``kv``), ``engine.sample``
+    around the tick's per-slot sampling (``n``) and one ``engine.retire``
+    per finished request (``rid``, ``tokens``, ``ticks``).
     """
 
     def __init__(self, cfg: ArchConfig, *, slots: int = 4, max_len: int = 128,
@@ -400,6 +411,13 @@ class ContinuousBatchingEngine(_EngineBase):
         self.caches = self._runner.init_cache(slots, max_len)
         self.done: list[Result] = []
         self._key = jax.random.PRNGKey(seed + 1)
+        # perf_counter at submit, by id() of the queued request: the
+        # admission span's queue wait
+        self._submitted: dict[int, float] = {}
+
+    def submit(self, req: Request):
+        self._submitted[id(req)] = time.perf_counter()
+        super().submit(req)
 
     # ------------------------------------------------------------------
     # predicted admission
@@ -485,31 +503,44 @@ class ContinuousBatchingEngine(_EngineBase):
             req = self.queue.popleft()
             L = len(req.prompt)
             t0 = time.perf_counter()
-            if self.recorder is not None:
-                # per-slot admission prefills recompute the prompt alone
-                self.recorder.record_step(
-                    f"admit#{req.rid}[L{L}]", self.cfg, 1, L, L, phase="prefill"
-                )
+            wait_ms = (t0 - self._submitted.pop(id(req), t0)) * 1e3
+            with TraceAnnotation("engine.admit", rid=req.rid, prompt_len=L,
+                                 queue_wait_ms=wait_ms, queued=len(self.queue)):
+                self._admit_into(i, slot, req, t0)
+
+    def _admit_into(self, i: int, slot: _Slot, req: Request, t0: float):
+        """Prefill ``req`` alone, write its KV into slot ``i`` and sample
+        its first token."""
+        L = len(req.prompt)
+        if self.recorder is not None:
+            # per-slot admission prefills recompute the prompt alone
+            self.recorder.record_step(
+                f"admit#{req.rid}[L{L}]", self.cfg, 1, L, L, phase="prefill"
+            )
+        with TraceAnnotation("engine.prefill"):
             batch = {"tokens": jnp.asarray(req.prompt, jnp.int32)[None, :]}
             logits, cache1 = self._runner.prefill(batch)
+        with TraceAnnotation("engine.pad_cache"):
             cache1 = self._runner.grow_cache(cache1, self.max_len)
-            # copy this request's KV rows into slot i of the shared cache
-            # (supported families' cache leaves are (n_layers, B, S, H, D):
-            # the slot axis is always 1)
+        # copy this request's KV rows into slot i of the shared cache
+        # (supported families' cache leaves are (n_layers, B, S, H, D):
+        # the slot axis is always 1)
+        with TraceAnnotation("engine.slot_write"):
             self.caches = jax.tree.map(
                 lambda full, one: full.at[:, i].set(one[:, 0]),
                 self.caches,
                 cache1,
             )
-            self._key, sub = jax.random.split(self._key)
+        self._key, sub = jax.random.split(self._key)
+        with TraceAnnotation("engine.first_token"):
             tok = self._sample_one(logits[0], req, sub)
-            now = time.perf_counter()
-            slot.req, slot.pos, slot.emitted, slot.cur = req, L, [tok], tok
-            slot.t_admit, slot.prefill_s, slot.ticks = t0, now - t0, 1
-            if self.recorder is not None:
-                # the admit step's wall-clock == the slot's prefill_s, so
-                # trace residuals reproduce Result-derived ones exactly
-                self.recorder.mark_measured(slot.prefill_s)
+        now = time.perf_counter()
+        slot.req, slot.pos, slot.emitted, slot.cur = req, L, [tok], tok
+        slot.t_admit, slot.prefill_s, slot.ticks = t0, now - t0, 1
+        if self.recorder is not None:
+            # the admit step's wall-clock == the slot's prefill_s, so
+            # trace residuals reproduce Result-derived ones exactly
+            self.recorder.mark_measured(slot.prefill_s)
 
     def _sample_one(self, logits, req, key) -> int:
         logits = logits[: self.cfg.vocab_size]
@@ -517,8 +548,26 @@ class ContinuousBatchingEngine(_EngineBase):
             return int(jax.random.categorical(key, logits / req.temperature))
         return int(jnp.argmax(logits))
 
+    def _retire(self, i: int):
+        s = self.slots[i]
+        with TraceAnnotation("engine.retire", rid=s.req.rid, tokens=len(s.emitted),
+                             ticks=s.ticks):
+            now = time.perf_counter()
+            self.done.append(
+                Result(
+                    s.req.rid, s.emitted, s.prefill_s,
+                    max(now - s.t_admit - s.prefill_s, 0.0),
+                    ticks=s.ticks, latency_s=now - s.t_admit,
+                )
+            )
+            self.slots[i] = _Slot()
+
     def step(self):
         """One scheduler tick: admit, decode all active slots, retire."""
+        with TraceAnnotation("engine.step"):
+            return self._step()
+
+    def _step(self) -> bool:
         self._admit()
         active = [i for i, s in enumerate(self.slots) if not s.free]
         if not active:
@@ -527,35 +576,33 @@ class ContinuousBatchingEngine(_EngineBase):
         pos = jnp.asarray(
             [min(s.pos, self.max_len - 1) for s in self.slots], jnp.int32
         )
+        # lock-step decode launches over the full slot pool; the padded
+        # batch attends up to the most advanced active position
+        kv = max(min(self.slots[i].pos, self.max_len - 1) for i in active) + 1
         if self.recorder is not None:
-            # lock-step decode launches over the full slot pool; the padded
-            # batch attends up to the most advanced active position
-            kv = max(min(self.slots[i].pos, self.max_len - 1) for i in active) + 1
             self.recorder.record_step(
                 f"tick[{len(active)}/{len(self.slots)}]",
                 self.cfg, len(self.slots), 1, kv,
                 phase="decode", active=len(active),
             )
         t_tick = time.perf_counter()
-        logits, self.caches = self._runner.decode(self.caches, toks, pos)
-        for i in active:
-            s = self.slots[i]
-            self._key, sub = jax.random.split(self._key)
-            tok = self._sample_one(logits[i], s.req, sub)
-            s.emitted.append(tok)
-            s.pos += 1
-            s.cur = tok
-            s.ticks += 1
-            if len(s.emitted) >= s.req.max_new or s.pos >= self.max_len - 1:
-                now = time.perf_counter()
-                self.done.append(
-                    Result(
-                        s.req.rid, s.emitted, s.prefill_s,
-                        max(now - s.t_admit - s.prefill_s, 0.0),
-                        ticks=s.ticks, latency_s=now - s.t_admit,
-                    )
-                )
-                self.slots[i] = _Slot()
+        with TraceAnnotation("engine.decode", active=len(active), slots=len(self.slots),
+                             kv=kv):
+            logits, self.caches = self._runner.decode(self.caches, toks, pos)
+        finished = []
+        with TraceAnnotation("engine.sample", n=len(active)):
+            for i in active:
+                s = self.slots[i]
+                self._key, sub = jax.random.split(self._key)
+                tok = self._sample_one(logits[i], s.req, sub)
+                s.emitted.append(tok)
+                s.pos += 1
+                s.cur = tok
+                s.ticks += 1
+                if len(s.emitted) >= s.req.max_new or s.pos >= self.max_len - 1:
+                    finished.append(i)
+        for i in finished:
+            self._retire(i)
         if self.recorder is not None:
             # the per-slot int() sampling above synced the tick
             self.recorder.mark_measured(time.perf_counter() - t_tick)
