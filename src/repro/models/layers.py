@@ -382,32 +382,69 @@ def attention_layer(
     return out, (k, v)
 
 
+#: lanes of a TPU vector register
+LANES = 128
+
+
+def _write_rows(stack, layer, row, new):
+    """Write ``new[b]`` (Hkv, D) at ``(*layer, b, row[b])`` of the stacked
+    cache ``stack`` in place.
+
+    A head dim of whole lanes stays the minor axis of the cache in TPU
+    memory, and one scatter writes every slot's row. Otherwise the device
+    keeps the sequence axis minor, where a scatter would have the whole
+    stack relaid out on the way in and out; each slot then rewrites the
+    lane-wide block of positions that holds its row."""
+    new = new.astype(stack.dtype)
+    B, S = new.shape[0], stack.shape[-3]
+    if new.shape[-1] % LANES == 0:
+        return stack.at[(*layer, jnp.arange(B), row)].set(
+            new, unique_indices=True, mode="promise_in_bounds"
+        )
+    w = min(LANES, S)
+    zero = jnp.zeros((), jnp.int32)
+    for b in range(B):
+        start = jnp.clip(row[b] // w * w, 0, S - w)
+        at = (*layer, jnp.int32(b), start, zero, zero)
+        block = lax.dynamic_slice(stack, at, (1,) * (len(layer) + 1) + (w, *new.shape[1:]))
+        hit = (jnp.arange(w) == row[b] - start)[:, None, None]
+        stack = lax.dynamic_update_slice(stack, jnp.where(hit, new[b], block), at)
+    return stack
+
+
 @jax.named_scope("attention")
 def attention_decode(
     p,
     x,  # (B, 1, d)
     cfg: ArchConfig,
-    cache_k,  # (B, Smax, Hkv, D)
+    cache_k,  # (*layers, B, Smax, Hkv, D): every layer's keys
     cache_v,
+    layer,  # tuple of scalar indices into the leading layer axes
     positions,  # (B,) current absolute position of the new token
     *,
     window: Optional[int],
 ):
-    """Single-token decode against a KV cache; returns (out, new_k, new_v)."""
+    """Single-token decode against one layer of the stacked KV caches.
+
+    Writes the B new rows in place at ``(*layer, slot, position)``, a
+    position past the end to the last row as ``dynamic_update_slice``
+    clamps it, then attends over that layer's slab read from the stack.
+    Returns (out, cache_k, cache_v) with the stacks updated."""
     B = x.shape[0]
     q, k, v = _project_qkv(p, x, cfg, positions[:, None])
+    Smax = cache_k.shape[-3]
     with jax.named_scope("kv_cache"):
-        cache_k = jax.vmap(lambda c, u, i: lax.dynamic_update_slice_in_dim(c, u, i, 0))(
-            cache_k, k, positions
-        )
-        cache_v = jax.vmap(lambda c, u, i: lax.dynamic_update_slice_in_dim(c, u, i, 0))(
-            cache_v, v, positions
-        )
-    Smax = cache_k.shape[1]
+        row = jnp.clip(positions, 0, Smax - 1)
+        cache_k = _write_rows(cache_k, layer, row, k[:, 0])
+        cache_v = _write_rows(cache_v, layer, row, v[:, 0])
+    slab_k, slab_v = cache_k, cache_v
+    for i in layer:
+        slab_k = lax.dynamic_index_in_dim(slab_k, i, 0, keepdims=False)
+        slab_v = lax.dynamic_index_in_dim(slab_v, i, 0, keepdims=False)
     kpos = jnp.broadcast_to(jnp.arange(Smax, dtype=jnp.int32)[None], (B, Smax))
     valid = kpos <= positions[:, None]
     out = chunked_attention(
-        q, cache_k, cache_v, positions[:, None], kpos,
+        q, slab_k, slab_v, positions[:, None], kpos,
         causal=True, window=window, softcap=cfg.attn_softcap,
         q_block=cfg.q_block, kv_valid=valid, prefix=cfg.meta_tokens,
     )

@@ -38,9 +38,28 @@ class Ctx:
     enc_out: Optional[jax.Array] = None  # whisper encoder output (B, F, d)
 
 
+@partial(jax.tree_util.register_dataclass, data_fields=["stack", "layer"], meta_fields=[])
+@dataclasses.dataclass
+class KVStack:
+    """One layer's self-attention K or V cache as decode sees it: the whole
+    stacked cache ``(*layers, B, S, H, D)`` and the layer's index on each
+    leading layer axis, so the layer writes its new rows into the stack."""
+
+    stack: jax.Array
+    layer: tuple
+
+
+def _is_stacked(a) -> bool:
+    return isinstance(a, KVStack)
+
+
+def _leaf_name(path) -> str:
+    return path[-1].key if hasattr(path[-1], "key") else str(path[-1])
+
+
 def _cast(p, dtype, keep_f32=("A_log", "dt_bias", "D")):
     def f(path, a):
-        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
+        name = _leaf_name(path)
         if a.dtype == jnp.float32 and name in keep_f32:
             return a
         return a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a
@@ -56,10 +75,11 @@ def _cast(p, dtype, keep_f32=("A_log", "dt_bias", "D")):
 def _self_attn(p, x, ctx: Ctx, cache, mode, *, window, causal=True):
     cfg = ctx.cfg
     if mode == "decode":
+        k, v = cache["k"], cache["v"]
         out, ck, cv = L.attention_decode(
-            p, x, cfg, cache["k"], cache["v"], ctx.dec_positions, window=window
+            p, x, cfg, k.stack, v.stack, k.layer, ctx.dec_positions, window=window
         )
-        return out, {"k": ck, "v": cv}
+        return out, {"k": KVStack(ck, k.layer), "v": KVStack(cv, v.layer)}
     # attn_shard_hint: True = always, "train" = training only (§Perf It-7:
     # the prefill cache out-sharding interplay made the hint regress on
     # gemma2 prefill, while training-graph psums still benefit)
@@ -233,14 +253,18 @@ def init_cross_block(key, cfg: ArchConfig, dtype):
 def vlm_group(p, x, ctx: Ctx, cache, mode):
     """cross_every self-attn layers followed by one gated cross-attn layer."""
     cache = cache or {"self": None, "cross": None}
+    self_block = partial(dense_block, window=None)
+    if mode == "decode":
+        x, self_caches = _decode_scan(self_block, p["self"], x, ctx, cache["self"])
+        aux = 0.0
+    else:
 
-    def inner(carry, xs):
-        x, aux = carry
-        lp, lc = xs
-        x, a, c = dense_block(lp, x, ctx, lc, mode, window=None)
-        return (x, aux + a), c
+        def inner(carry, lp):
+            x, aux = carry
+            x, a, c = self_block(lp, x, ctx, None, mode)
+            return (x, aux + a), c
 
-    (x, aux), self_caches = lax.scan(inner, (x, 0.0), (p["self"], cache["self"]))
+        (x, aux), self_caches = lax.scan(inner, (x, 0.0), p["self"])
     x, a2, cross_cache = cross_block(p["cross"], x, ctx, cache["cross"], mode)
     new_cache = None
     if mode != "train":
@@ -312,6 +336,52 @@ def enc_block(p, x, ctx: Ctx, cache, mode):
 # ======================================================================
 
 
+def _layer_view(cache, i):
+    """Layer ``i`` of a stacked cache as the block bodies take it: K/V
+    leaves with a sequence axis (as ``pad_cache`` tells them) become
+    ``KVStack``s, every other leaf (SSM state, cross-attention K/V) is
+    sliced out."""
+
+    def f(path, a):
+        if _is_stacked(a):
+            return KVStack(a.stack, (*a.layer, i))
+        if _leaf_name(path) in ("k", "v") and a.ndim >= 5:
+            return KVStack(a, (i,))
+        return lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+
+    return jax.tree_util.tree_map_with_path(f, cache, is_leaf=_is_stacked)
+
+
+def _restack(cache, view, new, i):
+    """Fold layer ``i``'s returned cache back into the stack: K/V stacks
+    were updated in place, a leaf returned as it was given stays, and any
+    other leaf is written at ``i``."""
+
+    def f(old, given, got):
+        if _is_stacked(got):
+            return KVStack(got.stack, old.layer) if _is_stacked(old) else got.stack
+        if got is given:
+            return old
+        return lax.dynamic_update_index_in_dim(old, got.astype(old.dtype), i, 0)
+
+    return jax.tree.map(f, cache, view, new, is_leaf=_is_stacked)
+
+
+def _decode_scan(fwd, params, x, ctx: Ctx, cache):
+    """Decode one token through a stack of layers, carrying the stacked
+    cache and the layer index: each layer writes only its new K/V rows, so
+    no layer's cache is sliced out, restacked or copied at the end."""
+
+    def body(carry, lp):
+        x, cache, i = carry
+        view = _layer_view(cache, i)
+        x, _, new = fwd(lp, x, ctx, view, "decode")
+        return (x, _restack(cache, view, new, i), i + 1), None
+
+    (x, cache, _), _ = lax.scan(body, (x, cache, jnp.int32(0)), params)
+    return x, cache
+
+
 @dataclasses.dataclass
 class Segment:
     name: str
@@ -324,8 +394,9 @@ class Segment:
 
     @jax.named_scope("layer_scan")
     def apply(self, params, x, ctx: Ctx, mode: str, cache=None, remat=False):
-        """Scan the stack; its slicing and restacking of weights and
-        caches lands under the ``layer_scan`` scope."""
+        """Scan the stack under the ``layer_scan`` scope: the loop, its
+        slicing of each layer's weights and, in decode, the carried cache
+        with its in-place row writes (``_decode_scan``)."""
         fwd = self.fwd
 
         if mode == "train":
@@ -355,14 +426,8 @@ class Segment:
             (x, aux), caches = lax.scan(body, (x, 0.0), params)
             return x, aux, caches
 
-        # decode
-        def body(x, xs):
-            lp, lc = xs
-            x, _, c = fwd(lp, x, ctx, lc, mode)
-            return x, c
-
-        x, caches = lax.scan(body, x, (params, cache))
-        return x, 0.0, caches
+        x, cache = _decode_scan(fwd, params, x, ctx, cache)
+        return x, 0.0, cache
 
 
 def build_segments(cfg: ArchConfig) -> list[Segment]:
@@ -585,8 +650,7 @@ def pad_cache(caches, cfg: ArchConfig, max_len: int):
     target = max_len + (cfg.meta_tokens or 0)
 
     def f(path, leaf):
-        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        if name in ("k", "v") and leaf.ndim >= 4:
+        if _leaf_name(path) in ("k", "v") and leaf.ndim >= 4:
             cur = leaf.shape[-3]
             if cur < target:
                 pads = [(0, 0)] * leaf.ndim

@@ -194,9 +194,28 @@ def test_programs_carry_the_scopes_and_keep_their_jit_names(program, text, scope
     assert scopes <= set(hlo_scopes(hlo).values())
 
 
-def test_the_scans_restack_of_the_cache_lands_under_layer_scan():
-    hlo = _decode_text(get_arch("stablelm-3b").smoke())
-    assert re.search(r'dynamic-update-slice\(.*op_name="jit\(decode\)/layer_scan/while/body/'
-                     r'dynamic_update_slice"', hlo)
-    assert re.search(r'op_name="jit\(decode\)/layer_scan/while/body/closed_call/attention/'
-                     r'kv_cache/', hlo)
+def _stack_writes(hlo: str, shape) -> list[tuple[str, str]]:
+    """(opcode, op_name) of each instruction that writes or copies a whole
+    array of ``shape`` (any dtype, in any computation)."""
+    dims = ",".join(map(str, shape))
+    pat = re.compile(rf"%[\w.-]+ = \w+\[{dims}\]\S* (dynamic-update-slice|scatter|copy)\((.*)")
+    out = []
+    for m in pat.finditer(hlo):
+        name = re.search(r'op_name="([^"]*)"', m.group(2))
+        out.append((m.group(1), name.group(1) if name else ""))
+    return out
+
+
+@pytest.mark.parametrize("arch", ["stablelm-3b", "qwen3-0.6b"])
+def test_decode_writes_only_the_new_rows_into_the_stacked_cache(arch):
+    """The decode scan carries the stacked cache: no layer's cache is
+    restacked and the whole stack is never copied; the only writes of its
+    shape are the new rows', under ``layer_scan`` and ``kv_cache``."""
+    cfg = get_arch(arch).smoke()
+    hlo = _decode_text(cfg)
+    caches = jax.eval_shape(lambda: build_model(cfg).init_cache(SLOTS, MAX_LEN))
+    writes = _stack_writes(hlo, jax.tree.leaves(caches)[0].shape)
+    assert writes
+    row_write = re.compile(r"^jit\(decode\)/layer_scan/while/body/.*attention/kv_cache/")
+    for op, name in writes:
+        assert op != "copy" and row_write.match(name), (op, name)

@@ -124,3 +124,65 @@ def test_param_counts_match_analytical():
         real = sum(x.size for x in jax.tree.leaves(params))
         approx = cfg.n_params()
         assert abs(real - approx) / real < 0.15, (name, real, approx)
+
+
+def _kv_leaves(caches):
+    """Every self-attention K/V leaf of a cache tree, ``(n, B, S, H, D)``."""
+    return [a for path, a in jax.tree_util.tree_leaves_with_path(caches)
+            if path[-1].key in ("k", "v")]
+
+
+@pytest.mark.parametrize("cfg", [
+    smoke_cfg("qwen3-0.6b"),
+    dataclasses.replace(smoke_cfg("qwen3-0.6b"), head_dim=128),  # rows scattered whole
+    smoke_cfg("gemma2-2b"),
+    smoke_cfg("dbrx-132b"),
+], ids=["dense", "dense-hd128", "pairs", "moe"])
+def test_batched_decode_matches_a_per_slot_loop(cfg):
+    """Slots at different positions, from 0 to past the cache's end, decode
+    together as each does alone: the same greedy tokens, logits within the
+    bf16 tolerance and the same rows written. A position past the end
+    rewrites the last row at every step, and every row no step wrote is
+    left bit for bit."""
+    max_len, steps = 200, 3
+    start = np.array([0, 127, 150, max_len - 1], np.int32)
+    api = build_model(cfg)
+    params = api.init(jax.random.PRNGKey(4))
+    shapes = api.init_cache(len(start), max_len)
+    keys = jax.random.split(jax.random.PRNGKey(5), len(jax.tree.leaves(shapes)))
+    caches = jax.tree.unflatten(jax.tree.structure(shapes), [
+        jax.random.normal(k, a.shape).astype(a.dtype)
+        for k, a in zip(keys, jax.tree.leaves(shapes))])
+    decode = jax.jit(api.decode)
+    first = jnp.array([5, 17, 29, 41], jnp.int32)
+
+    def run(caches, tok, pos0):
+        """Greedy steps from ``pos0``: logits (B, steps, V), tokens
+        (B, steps), and the K/V leaves before and after each step."""
+        logits_seen, toks, kv = [], [], [[np.asarray(a) for a in _kv_leaves(caches)]]
+        for i in range(steps):
+            logits, caches = decode(params, caches, tok, jnp.asarray(pos0 + i))
+            tok = jnp.argmax(logits[:, : cfg.vocab_size], axis=-1).astype(jnp.int32)
+            logits_seen.append(np.asarray(logits, np.float32))
+            toks.append(np.asarray(tok))
+            kv.append([np.asarray(a) for a in _kv_leaves(caches)])
+        return np.stack(logits_seen, 1), np.stack(toks, 1), kv
+
+    logits, toks, kv = run(caches, first, start)
+    written = np.zeros((len(start), max_len), bool)
+    for b in range(len(start)):
+        one = jax.tree.map(lambda a: a[:, b : b + 1], caches)
+        logits_b, toks_b, kv_b = run(one, first[b : b + 1], start[b : b + 1])
+        np.testing.assert_array_equal(toks[b], toks_b[0])
+        np.testing.assert_allclose(logits[b], logits_b[0], rtol=8e-2, atol=8e-2)
+        rows = np.minimum(start[b] + np.arange(steps), max_len - 1)
+        written[b, rows] = True
+        for got, want in zip(kv[-1], kv_b[-1]):
+            np.testing.assert_allclose(got[:, b, rows].astype(np.float32),
+                                       want[:, 0, rows].astype(np.float32),
+                                       rtol=8e-2, atol=8e-2)
+        for i in range(steps):  # each step rewrites its row in every layer
+            for prev, cur in zip(kv[i], kv[i + 1]):
+                assert (prev[:, b, rows[i]] != cur[:, b, rows[i]]).any(axis=(-2, -1)).all()
+    for old, new in zip(kv[0], kv[-1]):
+        np.testing.assert_array_equal(new[:, ~written], old[:, ~written])

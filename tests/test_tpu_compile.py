@@ -1,4 +1,5 @@
-"""Every Pallas kernel compiles for a TPU v5e at real model widths.
+"""Every Pallas kernel compiles for a TPU v5e at real model widths, and the
+serving decode step updates its stacked KV cache in place there.
 
 The TPU compiler is installed with JAX and compiles for a chip that is
 described, not attached, so these tests need no accelerator. The topology is
@@ -6,6 +7,9 @@ described inside a module fixture (never at import), and everything built
 from it is built in fixtures or tests, so every pytest worker collects the
 same tests and only the worker that runs this file loads the TPU library.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -19,6 +23,7 @@ from repro.kernels.fused_moe import ops as moe_ops
 from repro.kernels.rmsnorm import ops as rms_ops
 from repro.kernels.scaled_mm import ops as mm_ops
 from repro.kernels.silu_mul import ops as silu_ops
+from repro.models.registry import build_model
 
 QWEN = get_arch("qwen3-0.6b")
 DBRX = get_arch("dbrx-132b")
@@ -92,3 +97,26 @@ def test_sp201_agrees_with_the_compiler(one_chip):
     assert check_blocks("silu_mul", kw, {"block_rows": 128}, hws=v5e) == []
     _compile(silu_ops.act_mul, shapes, one_chip, block_rows=128)
 
+
+
+@pytest.mark.parametrize("arch,slots,max_len", [
+    ("qwen3-0.6b", 12, 4096),  # head dim of whole lanes: one scatter of the rows
+    ("stablelm-3b", 3, 3200),  # head dim 80: the sequence axis is minor on the chip
+])
+def test_decode_updates_the_stacked_cache_in_place_on_v5e(arch, slots, max_len, one_chip):
+    """The decode step as the serving engine jits it, bf16 at full width:
+    the donated caches are updated in place, so the program needs no
+    scratch of the cache's size and never copies the stacked cache."""
+    api = build_model(dataclasses.replace(get_arch(arch), param_dtype="bfloat16"))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+                            tree)
+
+    params = on_chip(jax.eval_shape(api.init, jax.random.PRNGKey(0)))
+    caches = on_chip(jax.eval_shape(lambda: api.init_cache(slots, max_len)))
+    pos = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(api.decode, donate_argnums=(1,)).lower(params, caches, pos, pos).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    dims = ",".join(map(str, jax.tree.leaves(caches)[0].shape))
+    assert not re.search(rf"= bf16\[{dims}\]\S* copy\(", compiled.as_text())
